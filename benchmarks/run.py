@@ -78,6 +78,8 @@ def _parse_row(row: str) -> dict:
 
 
 def main(argv=None) -> None:
+    from repro.core.compile_cache import use_persistent_cache
+    use_persistent_cache()
     ap = argparse.ArgumentParser(
         prog="benchmarks/run.py",
         description="StreamDCIM repro benchmark harness")

@@ -637,9 +637,10 @@ def run_sweep(models: Optional[Sequence[str]] = None,
     if workers and workers > 1 and len(tasks) > 1:
         import multiprocessing as mp
         from concurrent.futures import ProcessPoolExecutor
-        ctx = (mp.get_context("fork")
-               if "fork" in mp.get_all_start_methods()
-               else mp.get_context())
+        # "spawn", never "fork": the parent may hold the accelerator,
+        # and a forked child would inherit that handle.  Spawned workers
+        # import modules but never touch a device.
+        ctx = mp.get_context("spawn")
         payload = [(name, seq, cal, hw, tuple(ems), stamp,
                     sim_cache.path if sim_cache is not None else None,
                     sim_cache is not None)

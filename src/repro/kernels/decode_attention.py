@@ -11,7 +11,8 @@ Grid: (batch, q_heads, kv_blocks) — kv innermost; the online-softmax
 state lives in VMEM scratch persisting across kv grid steps, the same
 discipline as ``flash_attention``.  GQA is handled in the K/V BlockSpec
 index map.  The single query row is lane-padded to ``block_q`` rows
-(TPU min tile); only row 0 is read back.
+(TPU min tile); only row 0 is read back.  The per-row lengths ride in
+as a scalar-prefetch operand (SMEM), so any batch size tiles.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ def _decode_kernel(clen_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
                    scale: float, window: int, bq: int, bk: int,
                    num_kv_blocks: int):
+    b = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -47,7 +49,7 @@ def _decode_kernel(clen_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    clen = clen_ref[0, 0]                                  # this row's length
+    clen = clen_ref[b]                                     # this row's length
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     mask = kpos < clen                                     # ragged + seq pad
     if window > 0:
@@ -100,9 +102,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     bk = max(min(block_k, W), 1)
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
-    # Lane-replicate per-row lengths so the kernel reads a (1, LANES)
-    # int32 block (scalar operands must still tile on TPU).
-    clen2 = jnp.broadcast_to(clen[:, None], (B, LANES))
 
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, BLOCK_Q - 1), (0, 0)))
     hd_pad = -(-hd // 128) * 128 - hd
@@ -121,23 +120,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         _decode_kernel, scale=scale, window=window, bq=BLOCK_Q, bk=bk,
         num_kv_blocks=nkb)
 
-    out = pl.pallas_call(
-        kernel,
+    # Index maps take the scalar-prefetch ref as a trailing argument.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hq, nkb),
         in_specs=[
-            pl.BlockSpec((1, LANES), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, 1, BLOCK_Q, hdp), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hdp), lambda b, h, j: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, bk, hdp), lambda b, h, j: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, BLOCK_Q, hdp),
+                         lambda b, h, j, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hdp), lambda b, h, j, _: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, bk, hdp), lambda b, h, j, _: (b, h // G, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, BLOCK_Q, hdp),
-                               lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, BLOCK_Q, hdp), q.dtype),
+                               lambda b, h, j, _: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
             pltpu.VMEM((BLOCK_Q, LANES), jnp.float32),
             pltpu.VMEM((BLOCK_Q, hdp), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, BLOCK_Q, hdp), q.dtype),
         interpret=interpret,
-    )(clen2, qp, k, v)
+    )(clen, qp, k, v)
     return out[:, :, :1, :hd]

@@ -4,27 +4,26 @@
         --shape train_4k --steps 100 [--smoke] [--mode tile_stream] \
         [--checkpoint-dir ckpts/run1] [--microbatches 4]
 
-``--smoke`` uses the arch's reduced config and a single-device mesh — the
-same code path that a v5e pod runs, minus the fleet.  On a real cluster
-each host runs this entrypoint under its own process index (jax
-distributed init is picked up from env vars when present).
+``--smoke`` uses the arch's reduced config and tiny shapes.  The mesh is
+built from the devices present (``launch.mesh.make_local_mesh``): one
+device runs the 1x1 mesh, a four-chip host shards over all four.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 
-import jax
-
 from repro.configs import registry
+from repro.core.compile_cache import use_persistent_cache
 from repro.core.types import ExecutionMode, SHAPES, ShapeConfig
 from repro.data.pipeline import SyntheticLM, TextCorpus
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.train import loop as L
 from repro.train import optimizer as OPT
 
 
 def main() -> None:
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(registry.ARCHS), required=True)
     ap.add_argument("--shape", choices=list(SHAPES), default="train_4k")
@@ -42,7 +41,6 @@ def main() -> None:
     ap.add_argument("--corpus", default=None,
                     help="path to local text corpus (default: synthetic)")
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
     cfg = registry.get_config(args.arch, smoke=args.smoke)
@@ -55,8 +53,7 @@ def main() -> None:
             shape, global_batch=args.global_batch or shape.global_batch,
             seq_len=args.seq_len or shape.seq_len)
 
-    mesh = make_host_mesh() if args.smoke or jax.device_count() == 1 \
-        else make_production_mesh(multi_pod=args.multi_pod)
+    mesh = make_local_mesh()
 
     source = (TextCorpus(cfg, shape, args.corpus) if args.corpus
               else SyntheticLM(cfg, shape))

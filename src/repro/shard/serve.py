@@ -18,8 +18,6 @@ from typing import Any, Dict
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.jax_compat import shard_map
-
 
 def mesh_prefill(mod, params, cfg, batch: Dict[str, Any], *, mesh,
                  max_len: int, **kwargs):
@@ -31,8 +29,8 @@ def mesh_prefill(mod, params, cfg, batch: Dict[str, Any], *, mesh,
     def fn(p, toks):
         return mod.prefill(p, cfg, {"tokens": toks}, max_len=max_len, **kw)
 
-    f = shard_map(fn, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-                  check=False)
+    f = jax.shard_map(fn, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                      check_vma=False)
     return f(params, batch["tokens"])
 
 
@@ -43,5 +41,5 @@ def mesh_decode_fn(mod, cfg, mesh):
     def fn(p, cache, tok):
         return mod.decode_step(p, cfg, cache, tok)
 
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P(), P(), P()),
-                             out_specs=P(), check=False))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P(), P(), P()),
+                                 out_specs=P(), check_vma=False))

@@ -510,8 +510,6 @@ def cost_analysis_cycles(fn: Callable, *args, hw=None) -> Tuple[int, int]:
     hw = hw or STREAMDCIM_BASE
     compiled = jax.jit(fn).lower(*args).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):            # older jax returns [dict]
-        ca = ca[0] if ca else {}
     flops = int(ca.get("flops", 0.0))
     per_cycle = (STREAMDCIM_ENERGY_BASE.macro_ops_per_cycle(hw)
                  * hw.num_macros)

@@ -2,10 +2,15 @@
 
 The batched path's correctness claim has two halves:
 
-* *bitwise* batched-vs-B=1 within each implementation — a bucket row's
-  online softmax never sees its neighbours, so slicing a row out of the
-  batched call must reproduce the B=1 call exactly (fp32), ragged
-  lengths and sliding-window edges included;
+* batched-vs-B=1 within each implementation — a bucket row's online
+  softmax never sees its neighbours, so slicing a row out of the batched
+  call must reproduce the B=1 call, ragged lengths and sliding-window
+  edges included.  The Pallas kernel runs one program per row, so the
+  match is bitwise.  The ``jnp`` reference is compiled by XLA, which
+  does not promise bit-identical fp32 reductions across batch sizes
+  (XLA's CPU backend vectorizes a B=3 reduction differently from a B=1
+  one), so its rows are held to ``JNP_ROW_TOL`` — a few fp32 ulps at
+  the O(1) magnitudes of these outputs;
 * *tolerance* across implementations — the batched kernels
   (``kernels.decode_attention`` Pallas, ``jnp_blocked`` reference)
   against the oracle ``ref_decode_attention`` and the per-slot
@@ -39,19 +44,33 @@ def _inputs(B=3, Hq=4, Hkv=2, W=48, hd=16, dtype=jnp.float32, seed=0):
 
 
 RAGGED = jnp.asarray([17, 48, 5], jnp.int32)      # mid / full / tiny
+JNP_ROW_TOL = 1e-6
+
+
+def _assert_row_matches(impl, row, solo, msg=""):
+    """Pallas rows are bitwise; jnp rows within ``JNP_ROW_TOL`` (see the
+    module docstring for why XLA's fp32 reductions are not bitwise)."""
+    if impl == "pallas":
+        assert jnp.array_equal(row, solo), msg
+    else:
+        np.testing.assert_allclose(np.asarray(row), np.asarray(solo),
+                                   rtol=JNP_ROW_TOL, atol=JNP_ROW_TOL,
+                                   err_msg=msg)
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_batched_equals_per_slot_bitwise_fp32(impl):
-    """fp32 bucket rows are bit-identical to B=1 calls of the same
-    implementation, per ragged row length."""
+    """fp32 bucket rows match B=1 calls of the same implementation, per
+    ragged row length: bit-identical for Pallas, within ``JNP_ROW_TOL``
+    for the XLA-compiled jnp reference."""
     q, k, v = _inputs()
     fn = (decode_attention_jnp if impl == "jnp"
           else lambda *a, **kw: decode_attention(*a, interpret=True, **kw))
     batched = fn(q, k, v, RAGGED)
     for i in range(q.shape[0]):
         solo = fn(q[i:i + 1], k[i:i + 1], v[i:i + 1], RAGGED[i])
-        assert jnp.array_equal(batched[i:i + 1], solo), (
+        _assert_row_matches(
+            impl, batched[i:i + 1], solo,
             f"{impl}: row {i} (len {int(RAGGED[i])}) differs from B=1")
 
 
@@ -69,7 +88,8 @@ def test_batched_matches_oracle(impl):
 @pytest.mark.parametrize("window", [1, 4, 5, 17, 48, 64])
 def test_sliding_window_edges(impl, window):
     """Window edges (1, == tiny row's len, around each len, > W) match
-    the oracle and stay batched-vs-B=1 bitwise."""
+    the oracle and keep batched rows equal to B=1 calls (bitwise for
+    Pallas, within ``JNP_ROW_TOL`` for jnp)."""
     q, k, v = _inputs()
     fn = (decode_attention_jnp if impl == "jnp"
           else lambda *a, **kw: decode_attention(*a, interpret=True, **kw))
@@ -79,7 +99,7 @@ def test_sliding_window_edges(impl, window):
     for i in range(q.shape[0]):
         solo = fn(q[i:i + 1], k[i:i + 1], v[i:i + 1], RAGGED[i],
                   window=window)
-        assert jnp.array_equal(out[i:i + 1], solo)
+        _assert_row_matches(impl, out[i:i + 1], solo)
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])

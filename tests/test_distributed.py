@@ -11,8 +11,8 @@ from repro.distributed import sharding as SH
 from repro.models import layers as L
 
 
-# Production axis sizes, simulated for rule evaluation (the test mesh is
-# single-device; jax.sharding.AxisType does not exist on jax 0.4.x).
+# Production axis sizes, simulated for rule evaluation: the test mesh is
+# single-device, and the rules read only axis sizes.
 PROD_SIZES = {"data": 16, "model": 16, "pod": 2}
 
 

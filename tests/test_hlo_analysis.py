@@ -64,8 +64,6 @@ def test_scan_remat_microbatch_exact():
     # XLA's own cost analysis must be a large undercount here (the reason
     # this analyzer exists)
     ca = jax.jit(train).lower(ws, xs).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per partition
-        ca = ca[0]
     assert ca["flops"] < 0.3 * expect
 
 
