@@ -25,6 +25,10 @@ cycles go* and what serving actually delivers:
                     DAG: the chain that bounds the makespan, per-resource
                     / per-op-class *critical* shares, exposed-rewrite
                     on-path cycles (§I, causally), slack histograms.
+``spans.py``        Named host spans on the profiler's clock
+                    (``TraceAnnotation``) and the counted host read
+                    ``host_read`` that ``serve.Engine`` and its K/V pool
+                    open around every layer boundary of the serving loop.
 ``whatif.py``       What-if projection: rescale event durations and
                     replay the DAG schedule — "R k× faster", "link
                     bandwidth k×", "ping-pong toggled" — plus the

@@ -35,6 +35,32 @@ Plans are compiled on admission from a bounded LRU cache
 servers neither re-scan the queue per admission nor grow the plan cache
 without limit.  The legacy ``mode=`` kwarg remains as a deprecation shim
 that bypasses the planner.
+
+Tracing (``repro.obs.spans``): ``run`` writes host spans into the
+profiler's trace, on the device planes' clock, at every layer boundary —
+
+    engine.step            one per schedule step (``step_num``)
+      engine.admit         one per admitted request (``rid``)
+        engine.prefill     dispatch of the model's prefill
+        engine.sync        the read of token #1
+        kv.write           ``PagedKVCache.admit`` (its length read an
+                           ``engine.sync``)
+      engine.plan_decode   ``decode_plan_for``
+      engine.decode        one per decode dispatch (``B``, ``kv``)
+        kv.gather          ``PagedKVCache.gather``
+        model.decode_step  dispatch of the jitted ``decode_step``
+        kv.scatter         ``PagedKVCache.scatter`` (its length read an
+                           ``engine.sync``)
+        engine.sync        the read of the bucket's tokens
+      engine.retire        freeing finished slots
+
+(the per-slot fallback opens ``engine.decode``, ``model.decode_step`` and
+``engine.sync`` per slot) — and counts, per ``run``, in ``registry``:
+``steps``, ``decode_calls``, ``decode_batches``, ``admissions`` and
+``host_syncs`` (every device-to-host read of the loop, each through
+``obs.spans.host_read``).  Device programs carry stable names:
+``jit_decode_step`` here, ``jit_kv_gather``/``jit_kv_scatter``/
+``jit_kv_write`` in the pool.
 """
 from __future__ import annotations
 
@@ -53,6 +79,7 @@ from repro.core.types import ExecutionMode, ModelConfig
 from repro.obs.metrics import (METRICS_SCHEMA_VERSION, MetricsRegistry,
                                RequestSpan, observe_spans, spans_from_steps,
                                spans_from_timeline, summarize_spans)
+from repro.obs.spans import host_read, span, step_span
 from repro.serve.kv_cache import PagedKVCache, shape_buckets
 from repro.serve.schedule import Schedule, ServeRequest, build_schedule
 
@@ -158,8 +185,12 @@ class Engine:
             from repro.shard.serve import mesh_decode_fn
             self._decode = mesh_decode_fn(self.mod, cfg, mesh)
         else:
-            self._decode = jax.jit(
-                lambda p, c, t: self.mod.decode_step(p, cfg, c, t))
+            mod = self.mod
+
+            def decode_step(p, c, t):
+                return mod.decode_step(p, cfg, c, t)
+
+            self._decode = jax.jit(decode_step)
         # Batched decode: mesh serving keeps per-slot B=1 calls (the
         # shard_map decode fn is traced for that shape); otherwise the
         # first admission decides — if its cache tree pages, the run
@@ -175,6 +206,7 @@ class Engine:
         self.last_schedule: Optional[Schedule] = None
         # Observability (DESIGN.md §12): per-run lifecycle bookkeeping.
         self.registry = MetricsRegistry()
+        self._syncs = self.registry.counter("host_syncs")
         self._arrivals: Dict[int, int] = {}
         self._step_walls: Dict[int, Tuple[float, float]] = {}
         self._prefill_wall_end: Dict[int, float] = {}
@@ -294,71 +326,95 @@ class Engine:
         self._pool = None
         batched = self.batch_decode
         self.registry = MetricsRegistry()
+        self._syncs = self.registry.counter("host_syncs")
         self._arrivals = {r.rid: r.arrival_step for r in reqs}
         self._step_walls = {}
         self._prefill_wall_end = {}
         V = self.cfg.vocab_size
         for st in schedule.steps:
-            wall0 = self._clock()
-            for slot, rid in st.admitted:
-                r = by_rid[rid]
-                last_logits, cache = self._prefill_one(r)
-                tok = jnp.argmax(last_logits[:, :V], axis=-1)[:, None]
-                r.out_tokens.append(int(tok[0, 0]))
-                # Token #1 just materialized: the wall-clock TTFT mark.
-                self._prefill_wall_end[rid] = self._clock()
-                if batched and self._pool is None:
-                    # First admission decides for the run: page the pool
-                    # or fall back per slot (SSM/MLA/hybrid/enc-dec
-                    # trees — every later cache shares the config).
-                    if PagedKVCache.supports(cache):
-                        self._pool = PagedKVCache.from_cache(
-                            cache, slots=self.slots,
-                            page_size=self.page_size)
+            with step_span("engine.step", step_num=st.step):
+                wall0 = self._clock()
+                for slot, rid in st.admitted:
+                    with span("engine.admit", rid=rid):
+                        batched = self._admit(slot, by_rid[rid], batched,
+                                              slot_state, V)
+                    rid_slot[rid] = slot
+                dp = None
+                step_buckets = None
+                if st.decoding:
+                    kv_lens = tuple(kv for _, _, kv in st.decoding)
+                    with span("engine.plan_decode"):
+                        dp = self.decode_plan_for(kv_lens)
+                    if self._pool is not None:
+                        step_buckets = self._decode_buckets(
+                            st, kv_lens, slot_state, V)
                     else:
-                        batched = False
-                if self._pool is not None:
-                    self._pool.admit(slot, cache)
-                    cache = None          # the pool owns the K/V now
-                slot_state[slot] = {"req": r, "cache": cache, "tok": tok}
-                rid_slot[rid] = slot
-            dp = None
-            step_buckets = None
-            if st.decoding:
-                kv_lens = tuple(kv for _, _, kv in st.decoding)
-                dp = self.decode_plan_for(kv_lens)
-                if self._pool is not None:
-                    step_buckets = self._decode_buckets(st, kv_lens,
-                                                        slot_state, V)
-                else:
-                    for slot, rid, _kv in st.decoding:
-                        ss = slot_state[slot]
-                        logits, ss["cache"] = self._decode(
-                            self.params, ss["cache"], ss["tok"])
-                        self.decode_calls += 1
-                        self.decode_batches += 1
-                        tok = jnp.argmax(logits[:, 0, :V], axis=-1)[:, None]
-                        ss["tok"] = tok
-                        ss["req"].out_tokens.append(int(tok[0, 0]))
-            self.step_log.append(StepRecord(
-                step=st.step,
-                admitted=tuple(r for _, r in st.admitted),
-                decoded=tuple(r for _, r, _ in st.decoding),
-                kv_lens=tuple(kv for _, _, kv in st.decoding),
-                decode_plan=dp,
-                buckets=step_buckets))
-            self._step_walls[st.step] = (wall0, self._clock())
-            for rid in st.finished:
-                done.append(by_rid[rid])
-                slot = rid_slot.pop(rid)
-                if self._pool is not None:
-                    self._pool.free(slot)               # recycle the pages
-                del slot_state[slot]                    # recycle the slot
+                        self._decode_per_slot(st, slot_state, V)
+                self.step_log.append(StepRecord(
+                    step=st.step,
+                    admitted=tuple(r for _, r in st.admitted),
+                    decoded=tuple(r for _, r, _ in st.decoding),
+                    kv_lens=tuple(kv for _, _, kv in st.decoding),
+                    decode_plan=dp,
+                    buckets=step_buckets))
+                self._step_walls[st.step] = (wall0, self._clock())
+                with span("engine.retire"):
+                    for rid in st.finished:
+                        done.append(by_rid[rid])
+                        slot = rid_slot.pop(rid)
+                        if self._pool is not None:
+                            self._pool.free(slot)       # recycle the pages
+                        del slot_state[slot]            # recycle the slot
         self.registry.counter("steps").inc(len(self.step_log))
         self.registry.counter("decode_calls").inc(self.decode_calls)
+        self.registry.counter("decode_batches").inc(self.decode_batches)
+        self.registry.counter("admissions").inc(
+            sum(len(rec.admitted) for rec in self.step_log))
         observe_spans(self.registry, self.request_spans, "steps.")
         observe_spans(self.registry, self.wall_spans, "wall.")
         return done
+
+    def _admit(self, slot: int, r: Request, batched: bool,
+               slot_state: Dict[int, Dict[str, Any]], V: int) -> bool:
+        """Prefill ``r`` into ``slot`` and emit its token #1; returns
+        whether the run still decodes through the pool."""
+        with span("engine.prefill"):
+            last_logits, cache = self._prefill_one(r)
+        tok = jnp.argmax(last_logits[:, :V], axis=-1)[:, None]
+        r.out_tokens.append(int(host_read(tok, self._syncs)[0, 0]))
+        # Token #1 just materialized: the wall-clock TTFT mark.
+        self._prefill_wall_end[r.rid] = self._clock()
+        if batched and self._pool is None:
+            # First admission decides for the run: page the pool or fall
+            # back per slot (SSM/MLA/hybrid/enc-dec trees — every later
+            # cache shares the config).
+            if PagedKVCache.supports(cache):
+                self._pool = PagedKVCache.from_cache(
+                    cache, slots=self.slots, page_size=self.page_size,
+                    syncs=self._syncs)
+            else:
+                batched = False
+        if self._pool is not None:
+            self._pool.admit(slot, cache)
+            cache = None                  # the pool owns the K/V now
+        slot_state[slot] = {"req": r, "cache": cache, "tok": tok}
+        return batched
+
+    def _decode_per_slot(self, st, slot_state, V) -> None:
+        """Advance each active slot with its own B=1 ``decode_step``
+        (cache trees the pool cannot page, and mesh serving)."""
+        for slot, _rid, kv in st.decoding:
+            with span("engine.decode", B=1, kv=kv):
+                ss = slot_state[slot]
+                with span("model.decode_step"):
+                    logits, ss["cache"] = self._decode(
+                        self.params, ss["cache"], ss["tok"])
+                self.decode_calls += 1
+                self.decode_batches += 1
+                tok = jnp.argmax(logits[:, 0, :V], axis=-1)[:, None]
+                ss["tok"] = tok
+                ss["req"].out_tokens.append(
+                    int(host_read(tok, self._syncs)[0, 0]))
 
     def decode_wall_s(self) -> float:
         """Wall seconds spent in pure-decode steps (steps that also
@@ -381,26 +437,29 @@ class Engine:
         for kv, positions in shape_buckets(kv_lens):
             slots = [st.decoding[p][0] for p in positions]
             rids = tuple(st.decoding[p][1] for p in positions)
-            # Bucket invariant: equal schedule KV length <=> equal cache
-            # position counter (kv counts the token being decoded, the
-            # cache holds everything before it).
-            for s in slots:
-                if self._pool.len_of(s) + 1 != kv:
-                    raise RuntimeError(
-                        f"slot {s}: cache len {self._pool.len_of(s)} "
-                        f"inconsistent with scheduled kv {kv}")
-            cache = self._pool.gather(slots)
-            toks = jnp.concatenate(
-                [slot_state[s]["tok"] for s in slots], axis=0)
-            logits, cache = self._decode(self.params, cache, toks)
-            self._pool.scatter(slots, cache)
-            self.decode_batches += 1
-            self.decode_calls += len(slots)
-            tok = jnp.argmax(logits[:, 0, :V], axis=-1)[:, None]
-            tok_np = np.asarray(tok)
-            for i, s in enumerate(slots):
-                slot_state[s]["tok"] = tok[i:i + 1]
-                slot_state[s]["req"].out_tokens.append(int(tok_np[i, 0]))
+            with span("engine.decode", B=len(slots), kv=kv):
+                # Bucket invariant: equal schedule KV length <=> equal
+                # cache position counter (kv counts the token being
+                # decoded, the cache holds everything before it).
+                for s in slots:
+                    if self._pool.len_of(s) + 1 != kv:
+                        raise RuntimeError(
+                            f"slot {s}: cache len {self._pool.len_of(s)} "
+                            f"inconsistent with scheduled kv {kv}")
+                cache = self._pool.gather(slots)
+                toks = jnp.concatenate(
+                    [slot_state[s]["tok"] for s in slots], axis=0)
+                with span("model.decode_step"):
+                    logits, cache = self._decode(self.params, cache, toks)
+                self._pool.scatter(slots, cache)
+                self.decode_batches += 1
+                self.decode_calls += len(slots)
+                tok = jnp.argmax(logits[:, 0, :V], axis=-1)[:, None]
+                tok_np = host_read(tok, self._syncs)
+                for i, s in enumerate(slots):
+                    slot_state[s]["tok"] = tok[i:i + 1]
+                    slot_state[s]["req"].out_tokens.append(
+                        int(tok_np[i, 0]))
             out.append((kv, rids))
         return tuple(out)
 

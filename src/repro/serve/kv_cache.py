@@ -22,15 +22,27 @@ slots share one call:
 Gather→compute→scatter round-trips are value-exact (pages are plain
 slices), so the batched path's numerics reduce to ``decode_step``'s own
 row independence — pinned by the batched-vs-B=1 parity tests.
+
+Each copy is one jitted program per pool side with a stable name in the
+device trace — ``kv_gather``, ``kv_scatter``, ``kv_write`` — taking the
+page ids as an int32 argument, so one program serves every slot set of a
+(bucket size, page count) shape.  The host spans ``kv.gather``,
+``kv.scatter`` and ``kv.write`` (``repro.obs.spans``) cover each call;
+``scatter`` and ``admit`` read the cache length through ``host_read``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.metrics import Counter
+from repro.obs.spans import host_read, span
 
 
 def shape_buckets(kv_lens: Sequence[int]
@@ -56,6 +68,50 @@ def shape_buckets(kv_lens: Sequence[int]
     return [(kv, tuple(members[kv])) for kv in order]
 
 
+@functools.partial(jax.jit, static_argnames="width")
+def kv_gather(pool, ids, width: int):
+    """Pack the pages ``ids`` (B, npg) of one pool side into a dense
+    ``(L, B, Hkv, width, hd)`` cache, zero past the pages' end."""
+    B, npg = ids.shape
+    _, L, Hkv, pg, hd = pool.shape
+    pages = jnp.take(pool, ids.reshape(-1), axis=0)
+    pages = pages.reshape(B, npg, L, Hkv, pg, hd)
+    dense = jnp.moveaxis(pages, 1, 3)          # (B, L, Hkv, npg, pg, hd)
+    dense = dense.reshape(B, L, Hkv, npg * pg, hd)
+    dense = jnp.moveaxis(dense, 0, 1)          # (L, B, Hkv, S, hd)
+    S = npg * pg
+    if S < width:
+        dense = jnp.pad(dense, ((0, 0), (0, 0), (0, 0), (0, width - S),
+                                (0, 0)))
+    return dense[:, :, :, :width]
+
+
+@jax.jit
+def kv_scatter(pool, ids, dense):
+    """Write the first ``npg`` pages' worth of each row of a dense
+    ``(L, B, Hkv, W, hd)`` cache to the pages ``ids`` (B, npg) of one
+    pool side; returns the new pool."""
+    B, npg = ids.shape
+    _, L, Hkv, pg, hd = pool.shape
+    S, W = npg * pg, dense.shape[3]
+    dense = jnp.moveaxis(dense, 1, 0)          # (B, L, Hkv, W, hd)
+    if S > W:
+        dense = jnp.pad(dense, ((0, 0), (0, 0), (0, 0), (0, S - W),
+                                (0, 0)))
+    pages = dense[:, :, :, :S].reshape(B, L, Hkv, npg, pg, hd)
+    pages = jnp.moveaxis(pages, 3, 1)          # (B, npg, L, Hkv, pg, hd)
+    return pool.at[ids.reshape(-1)].set(
+        pages.reshape(B * npg, L, Hkv, pg, hd))
+
+
+@jax.jit
+def kv_write(pool, ids, dense):
+    """Page out one B=1 cache leaf ``(L, 1, Hkv, W, hd)`` to the pages
+    ``ids`` (npg,) of one pool side: ``kv_scatter`` of a one-slot bucket,
+    under a name of its own so admissions and decode steps read apart."""
+    return kv_scatter(pool, ids[None], dense)
+
+
 @dataclasses.dataclass
 class _SlotEntry:
     pages: List[int]          # position-ordered page ids
@@ -68,12 +124,15 @@ class PagedKVCache:
     Built lazily from the first admitted cache (``from_cache``): the pool
     only supports the plain per-layer ``{"k", "v"}`` cache tree the
     unified transformer uses — families with richer state (SSM / MLA /
-    hybrid) stay on the engine's per-slot fallback.
+    hybrid) stay on the engine's per-slot fallback.  ``syncs``: the
+    counter each host read of a cache length increments (the engine's
+    ``host_syncs``).
     """
 
     def __init__(self, *, slots: int, num_layers: int, kv_heads: int,
                  width: int, head_dim: int, dtype,
-                 page_size: int = 64) -> None:
+                 page_size: int = 64,
+                 syncs: Optional[Counter] = None) -> None:
         if slots < 1 or width < 1:
             raise ValueError(f"slots ({slots}) and width ({width}) must "
                              "be >= 1")
@@ -91,6 +150,7 @@ class PagedKVCache:
         self._v_pool = jnp.zeros(shape, dtype)
         self._free: deque = deque(range(self.num_pages))
         self._table: Dict[int, _SlotEntry] = {}
+        self._syncs = syncs
 
     # ------------------------------------------------------------------
     # Construction / introspection
@@ -110,13 +170,14 @@ class PagedKVCache:
         return getattr(k, "ndim", 0) == 5 and k.shape[1] == 1
 
     @classmethod
-    def from_cache(cls, cache, *, slots: int,
-                   page_size: int = 64) -> "PagedKVCache":
+    def from_cache(cls, cache, *, slots: int, page_size: int = 64,
+                   syncs: Optional[Counter] = None) -> "PagedKVCache":
         """Size a pool from one admitted B=1 cache's leaf shapes."""
         k = cache["layers"]["k"]               # (L, 1, Hkv, W, hd)
         L, _, Hkv, W, hd = k.shape
         return cls(slots=slots, num_layers=L, kv_heads=Hkv, width=W,
-                   head_dim=hd, dtype=k.dtype, page_size=page_size)
+                   head_dim=hd, dtype=k.dtype, page_size=page_size,
+                   syncs=syncs)
 
     @property
     def pages_in_use(self) -> int:
@@ -155,12 +216,17 @@ class PagedKVCache:
         if not self.supports(cache):
             raise ValueError("cache tree is not the plain {'k','v'} "
                              "layout this pool pages")
-        entry = _SlotEntry(pages=[], length=int(cache["len"]))
-        self._alloc(entry, entry.length)
-        self._table[slot] = entry
-        if entry.pages:
-            self._write(entry, cache["layers"]["k"][:, 0],
-                        cache["layers"]["v"][:, 0])
+        with span("kv.write"):
+            entry = _SlotEntry(
+                pages=[], length=int(host_read(cache["len"], self._syncs)))
+            self._alloc(entry, entry.length)
+            self._table[slot] = entry
+            if entry.pages:
+                ids = np.asarray(entry.pages, np.int32)
+                self._k_pool = kv_write(self._k_pool, ids,
+                                        cache["layers"]["k"])
+                self._v_pool = kv_write(self._v_pool, ids,
+                                        cache["layers"]["v"])
 
     def free(self, slot: int) -> None:
         """Recycle a finished slot's pages back to the pool."""
@@ -179,96 +245,47 @@ class PagedKVCache:
         invariant).  Returns ``{"layers": {"k": (L, B, Hkv, W, hd),
         "v": ...}, "len": scalar}`` ready for one ``decode_step`` call.
         """
-        entries = [self._table[s] for s in slot_ids]
-        lens = {e.length for e in entries}
-        if len(lens) != 1:
-            raise ValueError(f"bucket slots {list(slot_ids)} hold unequal "
-                             f"lengths {sorted(lens)}")
-        length = entries[0].length
-        B = len(entries)
-        npg = self._pages_for(length)
-        if npg == 0:
-            k = jnp.zeros((self.num_layers, B, self.kv_heads, self.width,
-                           self.head_dim), self._k_pool.dtype)
-            return {"layers": {"k": k, "v": k},
+        with span("kv.gather"):
+            entries = [self._table[s] for s in slot_ids]
+            lens = {e.length for e in entries}
+            if len(lens) != 1:
+                raise ValueError(f"bucket slots {list(slot_ids)} hold "
+                                 f"unequal lengths {sorted(lens)}")
+            length = entries[0].length
+            npg = self._pages_for(length)
+            if npg == 0:
+                k = jnp.zeros((self.num_layers, len(entries), self.kv_heads,
+                               self.width, self.head_dim),
+                              self._k_pool.dtype)
+                v = k
+            else:
+                ids = np.asarray([e.pages[:npg] for e in entries], np.int32)
+                k = kv_gather(self._k_pool, ids, self.width)
+                v = kv_gather(self._v_pool, ids, self.width)
+            return {"layers": {"k": k, "v": v},
                     "len": jnp.asarray(length, jnp.int32)}
-        ids = np.asarray([e.pages[:npg] for e in entries], np.int32)
-
-        def pack(pool):
-            pages = jnp.take(pool, ids.reshape(-1), axis=0)
-            pages = pages.reshape(B, npg, self.num_layers, self.kv_heads,
-                                  self.page_size, self.head_dim)
-            dense = jnp.moveaxis(pages, 1, 3)      # (B, L, Hkv, npg, pg, hd)
-            dense = dense.reshape(B, self.num_layers, self.kv_heads,
-                                  npg * self.page_size, self.head_dim)
-            dense = jnp.moveaxis(dense, 0, 1)      # (L, B, Hkv, S, hd)
-            S = npg * self.page_size
-            if S < self.width:
-                dense = jnp.pad(
-                    dense, ((0, 0), (0, 0), (0, 0),
-                            (0, self.width - S), (0, 0)))
-            return dense[:, :, :, :self.width]
-
-        return {"layers": {"k": pack(self._k_pool),
-                           "v": pack(self._v_pool)},
-                "len": jnp.asarray(length, jnp.int32)}
 
     def scatter(self, slot_ids: Sequence[int], cache) -> None:
         """Write one advanced bucket cache back through the page tables,
         allocating the page each slot's growth step crossed into.
 
-        One ``.at[ids].set`` per pool for the whole bucket (equal lengths
-        => equal page counts) — a per-slot write-back loop would cost as
-        many eager dispatches as the batching saved.
+        One ``kv_scatter`` per pool side for the whole bucket (equal
+        lengths => equal page counts) — a per-slot write-back loop would
+        cost as many dispatches as the batching saved.
         """
-        new_len = int(cache["len"])
-        entries = [self._table[s] for s in slot_ids]
-        for e in entries:
-            if new_len < e.length:
-                raise ValueError("scatter would shrink a slot's cache")
-            self._alloc(e, new_len)
-            e.length = new_len
-        npg = self._pages_for(new_len)
-        if npg == 0:
-            return
-        B = len(entries)
-        S = npg * self.page_size
-        ids = np.asarray([e.pages[:npg] for e in entries],
-                         np.int32).reshape(-1)
-
-        def unpack(dense):
-            dense = jnp.moveaxis(dense, 1, 0)  # (B, L, Hkv, W, hd)
-            if S > self.width:
-                dense = jnp.pad(
-                    dense, ((0, 0), (0, 0), (0, 0),
-                            (0, S - self.width), (0, 0)))
-            pages = dense[:, :, :, :S].reshape(
-                B, self.num_layers, self.kv_heads, npg, self.page_size,
-                self.head_dim)
-            pages = jnp.moveaxis(pages, 3, 1)  # (B, npg, L, Hkv, pg, hd)
-            return pages.reshape(B * npg, self.num_layers, self.kv_heads,
-                                 self.page_size, self.head_dim)
-
-        self._k_pool = self._k_pool.at[ids].set(unpack(cache["layers"]["k"]))
-        self._v_pool = self._v_pool.at[ids].set(unpack(cache["layers"]["v"]))
-
-    def _write(self, entry: _SlotEntry, k, v) -> None:
-        """Page out one slot's dense (L, Hkv, W, hd) buffers."""
-        npg = len(entry.pages)
-        if npg == 0:
-            return
-        S = npg * self.page_size
-        if S > self.width:
-            pad = ((0, 0), (0, 0), (0, S - self.width), (0, 0))
-            k = jnp.pad(k, pad)
-            v = jnp.pad(v, pad)
-        ids = np.asarray(entry.pages, np.int32)
-
-        def unpack(dense):
-            pages = dense[:, :, :S].reshape(
-                self.num_layers, self.kv_heads, npg, self.page_size,
-                self.head_dim)
-            return jnp.moveaxis(pages, 2, 0)   # (npg, L, Hkv, pg, hd)
-
-        self._k_pool = self._k_pool.at[ids].set(unpack(k))
-        self._v_pool = self._v_pool.at[ids].set(unpack(v))
+        with span("kv.scatter"):
+            new_len = int(host_read(cache["len"], self._syncs))
+            entries = [self._table[s] for s in slot_ids]
+            for e in entries:
+                if new_len < e.length:
+                    raise ValueError("scatter would shrink a slot's cache")
+                self._alloc(e, new_len)
+                e.length = new_len
+            npg = self._pages_for(new_len)
+            if npg == 0:
+                return
+            ids = np.asarray([e.pages[:npg] for e in entries], np.int32)
+            self._k_pool = kv_scatter(self._k_pool, ids,
+                                      cache["layers"]["k"])
+            self._v_pool = kv_scatter(self._v_pool, ids,
+                                      cache["layers"]["v"])
