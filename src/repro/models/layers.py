@@ -237,9 +237,13 @@ def attention_forward_mrope(params: Params, cfg: ModelConfig, x: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 def rope_at(pos, head_dim: int, theta: float):
-    """sin/cos (1, hd//2) for a single dynamic position — O(hd), no table."""
+    """sin/cos for a dynamic position — O(hd), no table: (1, hd//2) for a
+    scalar ``pos``, (B, 1, hd//2) for one position per row ``(B,)``."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if pos.ndim:
+        ang = pos.astype(jnp.float32)[:, None, None] * freqs
+        return jnp.sin(ang), jnp.cos(ang)
     ang = pos.astype(jnp.float32) * freqs
     return jnp.sin(ang)[None], jnp.cos(ang)[None]
 
@@ -247,7 +251,13 @@ def rope_at(pos, head_dim: int, theta: float):
 def attention_decode(params: Params, cfg: ModelConfig, x: jax.Array,
                      cache: Params, *, use_rope: bool = True
                      ) -> Tuple[jax.Array, Params]:
-    """x: (B, 1, D) pre-normed; cache: {k: (B,Hkv,W,hd), v: ..., len: ()}.
+    """x: (B, 1, D) pre-normed; cache: {k: (B,Hkv,W,hd), v: ..., len}.
+
+    ``len`` is one position for every row, shape ``()``, or one per row,
+    ``(B,)`` (the engine's pooled slots of unequal K/V length): each row
+    then takes its own RoPE angle, write slot and valid length, and
+    attends only its own entries — positions past its length are masked
+    out of the softmax over the full width W.
 
     Sliding-window archs allocate W = min(max_len, window) and the cache is
     a *ring buffer* (slot = pos % W) — a 0.5M-token SWA stream runs in a
@@ -267,10 +277,17 @@ def attention_decode(params: Params, cfg: ModelConfig, x: jax.Array,
         q = apply_rope_bsd(q, sin_t, cos_t)
         k_new = apply_rope_bsd(k_new, sin_t, cos_t)
     slot = jax.lax.rem(pos, W)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k_new.astype(cache["k"].dtype), slot, 2)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v_new.astype(cache["v"].dtype), slot, 2)
+
+    def write(c, new):
+        new = new.astype(c.dtype)
+        if pos.ndim:                        # each row at its own slot
+            return jax.vmap(lambda r, n, i: jax.lax
+                            .dynamic_update_slice_in_dim(r, n, i, 1)
+                            )(c, new, slot)
+        return jax.lax.dynamic_update_slice_in_dim(c, new, slot, 2)
+
+    k_cache = write(cache["k"], k_new)
+    v_cache = write(cache["v"], v_new)
     is_ring = cfg.attn_kind == AttnKind.SLIDING
     valid = jnp.minimum(pos + 1, W) if is_ring else pos + 1
     out = ref.ref_decode_attention(q, k_cache, v_cache, valid, window=0)

@@ -284,6 +284,11 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
     The (layer-stacked) cache is scanned together with the layer params.
     MoE prefix layers (deepseek) share the same cache tensor layout, so we
     scan dense-prefix and moe stacks separately over cache slices.
+
+    ``cache["len"]`` is the position every row decodes at, shape ``()``,
+    or one position per row, ``(B,)``, for rows of unequal K/V length
+    (plain ``{"k", "v"}`` attention caches; ``L.attention_decode``); the
+    returned ``len`` keeps its shape.
     """
     x = L.embed_lookup(params["embed"], tokens)
     pos = cache["len"]

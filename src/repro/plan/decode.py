@@ -394,12 +394,12 @@ def plan_decode_buckets(cfg: ModelConfig,
                         ) -> List[Tuple[Tuple[int, ...], DecodePlan]]:
     """Plan one decode step as per-shape-bucket ``DecodePlan``s.
 
-    Slots with equal KV length share cache shape and position counter, so
-    the batched engine advances each such *bucket* with one
-    ``decode_step`` call.  Returns ``[(slot_positions, plan), ...]`` —
-    positions index into ``context``, buckets appear in order of their
-    first member — where each ``plan`` is ``plan_decode_step`` of that
-    bucket's (uniform) context.
+    Slots with equal KV length share cache shape and position counter —
+    the *bucket* the simulator's coarse decode lowering accounts by (the
+    engine advances all slots in one ``decode_step`` call).  Returns
+    ``[(slot_positions, plan), ...]`` — positions index into ``context``,
+    buckets appear in order of their first member — where each ``plan``
+    is ``plan_decode_step`` of that bucket's (uniform) context.
 
     Per-layer attention bytes/cycles and GEMM shapes are per-slot
     additive (the planner never couples slots), so bucket plans are exact

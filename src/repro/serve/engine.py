@@ -12,15 +12,17 @@ never pads out to a long neighbour's length.
 
 Decode is *batched* (DESIGN.md §15): active slots' caches live in a
 paged K/V pool (``repro.serve.kv_cache.PagedKVCache``), and each step
-the engine groups slots into shape buckets (equal KV length ⇒ equal
-cache shape and position counter), gathers each bucket into one packed
-cache and advances it with a single ``decode_step`` call —
-``decode_batches`` counts those calls while ``decode_calls`` keeps
-counting per-slot token advances, so ``decode_calls /
-decode_batches`` is the dispatch amplification the batching removes.
-Cache trees the pool cannot page (SSM / hybrid / MLA / enc-dec state,
-or mesh-sharded serving) transparently fall back to the per-slot B=1
-path with identical semantics.
+the engine gathers every decoding slot, whatever its K/V length, into
+one packed cache and advances it with a single ``decode_step`` call, so
+the step streams the weights once; each row decodes at its own position
+and is masked by its own length (``cache["len"]`` of shape ``(B,)``).
+``decode_batches`` counts those calls (one per step that decodes) while
+``decode_calls`` keeps counting per-slot token advances, so
+``decode_calls / decode_batches`` is the dispatch amplification the
+batching removes; ``decode_ragged`` counts the calls whose rows hold
+more than one K/V length.  Cache trees the pool cannot page (SSM /
+hybrid / MLA / enc-dec state, or mesh-sharded serving) transparently
+fall back to the per-slot B=1 path with identical semantics.
 
 The step timeline is the *shared* deterministic schedule
 (``repro.serve.schedule.build_schedule``), the same object
@@ -46,21 +48,22 @@ profiler's trace, on the device planes' clock, at every layer boundary —
         kv.write           ``PagedKVCache.admit`` (its length read an
                            ``engine.sync``)
       engine.plan_decode   ``decode_plan_for``
-      engine.decode        one per decode dispatch (``B``, ``kv``)
+      engine.decode        one per decode dispatch (``B``, ``kv``: the
+                           widest K/V length of the dispatch)
         kv.gather          ``PagedKVCache.gather``
         model.decode_step  dispatch of the jitted ``decode_step``
-        kv.scatter         ``PagedKVCache.scatter`` (its length read an
-                           ``engine.sync``)
-        engine.sync        the read of the bucket's tokens
+        kv.scatter         ``PagedKVCache.scatter`` (its rows' lengths
+                           read in one ``engine.sync``)
+        engine.sync        the read of the step's tokens
       engine.retire        freeing finished slots
 
 (the per-slot fallback opens ``engine.decode``, ``model.decode_step`` and
 ``engine.sync`` per slot) — and counts, per ``run``, in ``registry``:
-``steps``, ``decode_calls``, ``decode_batches``, ``admissions`` and
-``host_syncs`` (every device-to-host read of the loop, each through
-``obs.spans.host_read``).  Device programs carry stable names:
-``jit_decode_step`` here, ``jit_kv_gather``/``jit_kv_scatter``/
-``jit_kv_write`` in the pool.
+``steps``, ``decode_calls``, ``decode_batches``, ``decode_ragged``,
+``admissions`` and ``host_syncs`` (every device-to-host read of the
+loop, each through ``obs.spans.host_read``).  Device programs carry
+stable names: ``jit_decode_step`` here, ``jit_kv_gather``/
+``jit_kv_scatter``/``jit_kv_write`` in the pool.
 """
 from __future__ import annotations
 
@@ -80,7 +83,7 @@ from repro.obs.metrics import (METRICS_SCHEMA_VERSION, MetricsRegistry,
                                RequestSpan, observe_spans, spans_from_steps,
                                spans_from_timeline, summarize_spans)
 from repro.obs.spans import host_read, span, step_span
-from repro.serve.kv_cache import PagedKVCache, shape_buckets
+from repro.serve.kv_cache import PagedKVCache
 from repro.serve.schedule import Schedule, ServeRequest, build_schedule
 
 
@@ -103,8 +106,9 @@ class StepRecord:
     decoded: Tuple[int, ...]             # rids advanced one token
     kv_lens: Tuple[int, ...]             # per decoded slot: attended KV len
     decode_plan: Optional[object] = None  # the step's DecodePlan (or None)
-    # Shape buckets the step's decode actually dispatched: (kv_len, rids)
-    # per batched decode_step call; None on the per-slot fallback path.
+    # The step's batched decode_step call, as one (kv, rids) entry: kv is
+    # the widest K/V length of its rows (rids in dispatch order); None
+    # when the step decoded nothing and on the per-slot fallback path.
     buckets: Optional[Tuple[Tuple[int, Tuple[int, ...]], ...]] = None
 
 
@@ -157,10 +161,11 @@ class Engine:
         mesh (``launch.mesh`` builders); prefill/decode then run under
         ``shard_map`` with replicated specs (``repro.shard.serve``,
         DESIGN.md §13) — numerics identical to the mesh-less path.
-        ``batch_decode``: group equal-KV-length slots into one
-        ``decode_step`` call through a paged K/V pool of ``page_size``
-        positions per page (DESIGN.md §15); auto-falls back per slot
-        for cache trees the pool cannot page and under ``mesh``.
+        ``batch_decode``: advance all active slots, of any K/V
+        lengths, with one ``decode_step`` call a step through a paged
+        K/V pool of ``page_size`` positions per page (DESIGN.md §15);
+        auto-falls back per slot for cache trees the pool cannot page
+        and under ``mesh``.
         ``clock``: wall-time source (``time.perf_counter``-compatible)
         for the ``"wall"`` SLO stats — injectable so tests can pin
         percentiles deterministically."""
@@ -207,6 +212,7 @@ class Engine:
         # Observability (DESIGN.md §12): per-run lifecycle bookkeeping.
         self.registry = MetricsRegistry()
         self._syncs = self.registry.counter("host_syncs")
+        self._ragged = self.registry.counter("decode_ragged")
         self._arrivals: Dict[int, int] = {}
         self._step_walls: Dict[int, Tuple[float, float]] = {}
         self._prefill_wall_end: Dict[int, float] = {}
@@ -327,6 +333,7 @@ class Engine:
         batched = self.batch_decode
         self.registry = MetricsRegistry()
         self._syncs = self.registry.counter("host_syncs")
+        self._ragged = self.registry.counter("decode_ragged")
         self._arrivals = {r.rid: r.arrival_step for r in reqs}
         self._step_walls = {}
         self._prefill_wall_end = {}
@@ -346,7 +353,7 @@ class Engine:
                     with span("engine.plan_decode"):
                         dp = self.decode_plan_for(kv_lens)
                     if self._pool is not None:
-                        step_buckets = self._decode_buckets(
+                        step_buckets = self._decode_batch(
                             st, kv_lens, slot_state, V)
                     else:
                         self._decode_per_slot(st, slot_state, V)
@@ -396,7 +403,9 @@ class Engine:
                 batched = False
         if self._pool is not None:
             self._pool.admit(slot, cache)
-            cache = None                  # the pool owns the K/V now
+            # The pool owns the K/V now; the pooled step feeds each slot's
+            # last token from ``out_tokens``.
+            cache = tok = None
         slot_state[slot] = {"req": r, "cache": cache, "tok": tok}
         return batched
 
@@ -430,38 +439,37 @@ class Engine:
                     total += bounds[1] - bounds[0]
         return total
 
-    def _decode_buckets(self, st, kv_lens, slot_state, V):
-        """Advance one step's active slots bucket-by-bucket through the
-        paged pool; returns the (kv_len, rids) buckets dispatched."""
-        out = []
-        for kv, positions in shape_buckets(kv_lens):
-            slots = [st.decoding[p][0] for p in positions]
-            rids = tuple(st.decoding[p][1] for p in positions)
-            with span("engine.decode", B=len(slots), kv=kv):
-                # Bucket invariant: equal schedule KV length <=> equal
-                # cache position counter (kv counts the token being
-                # decoded, the cache holds everything before it).
-                for s in slots:
-                    if self._pool.len_of(s) + 1 != kv:
-                        raise RuntimeError(
-                            f"slot {s}: cache len {self._pool.len_of(s)} "
-                            f"inconsistent with scheduled kv {kv}")
-                cache = self._pool.gather(slots)
-                toks = jnp.concatenate(
-                    [slot_state[s]["tok"] for s in slots], axis=0)
-                with span("model.decode_step"):
-                    logits, cache = self._decode(self.params, cache, toks)
-                self._pool.scatter(slots, cache)
-                self.decode_batches += 1
-                self.decode_calls += len(slots)
-                tok = jnp.argmax(logits[:, 0, :V], axis=-1)[:, None]
-                tok_np = host_read(tok, self._syncs)
-                for i, s in enumerate(slots):
-                    slot_state[s]["tok"] = tok[i:i + 1]
-                    slot_state[s]["req"].out_tokens.append(
-                        int(tok_np[i, 0]))
-            out.append((kv, rids))
-        return tuple(out)
+    def _decode_batch(self, st, kv_lens, slot_state, V):
+        """Advance all of one step's active slots with one
+        ``decode_step`` call through the paged pool, each row at its own
+        K/V length; returns the step's ``((max kv, rids),)`` record."""
+        slots = [slot for slot, _, _ in st.decoding]
+        rids = tuple(rid for _, rid, _ in st.decoding)
+        kv = max(kv_lens)
+        with span("engine.decode", B=len(slots), kv=kv):
+            # Each row's schedule KV length counts the token being
+            # decoded; its cache holds everything before it.
+            for s, k in zip(slots, kv_lens):
+                if self._pool.len_of(s) + 1 != k:
+                    raise RuntimeError(
+                        f"slot {s}: cache len {self._pool.len_of(s)} "
+                        f"inconsistent with scheduled kv {k}")
+            cache = self._pool.gather(slots)
+            # Each slot's last token is on the host already: one
+            # transfer, not a device slice and a concatenate per row.
+            toks = jnp.asarray([[slot_state[s]["req"].out_tokens[-1]]
+                                for s in slots], jnp.int32)
+            with span("model.decode_step"):
+                logits, cache = self._decode(self.params, cache, toks)
+            self._pool.scatter(slots, cache)
+            self.decode_batches += 1
+            self.decode_calls += len(slots)
+            if len(set(kv_lens)) > 1:
+                self._ragged.inc()
+            tok = jnp.argmax(logits[:, 0, :V], axis=-1)
+            for s, t in zip(slots, host_read(tok, self._syncs).tolist()):
+                slot_state[s]["req"].out_tokens.append(t)
+        return ((kv, rids),)
 
     # ------------------------------------------------------------------
     # Introspection

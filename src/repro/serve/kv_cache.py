@@ -1,34 +1,41 @@
 """Paged/packed KV-cache pool for batched decode (DESIGN.md §15).
 
-The engine's per-slot caches are exact but dispatch-wasteful: at 64+
-concurrent slots, 64 B=1 ``decode_step`` calls per step dominate
-tokens/s.  This module gives the engine a *paged* layout so equal-shape
-slots share one call:
+The engine's per-slot caches are exact but dispatch-wasteful: each
+``decode_step`` call streams every weight from HBM, so B=1 calls per
+slot cost B weight streams a step.  This module gives the engine a
+*paged* layout so all active slots share one call:
 
 * one page pool per cache side (K and V), page-major:
-  ``(num_pages, layers, kv_heads, page_size, head_dim)`` — a page holds
-  ``page_size`` consecutive cache positions of one slot across every
-  layer;
+  ``(num_pages + 1, layers, kv_heads, page_size, head_dim)`` — a page
+  holds ``page_size`` consecutive cache positions of one slot across
+  every layer; the last page is a spare that no slot owns;
 * a per-slot page table (position-ordered page ids) plus the slot's
   valid length; pages allocate on demand as the cache grows and return
   to the free list when the slot recycles;
-* ``gather`` packs a *shape bucket* — slots with equal KV length, found
-  by ``shape_buckets`` — into one batched cache
-  ``{"layers": {"k": (L, B, Hkv, W, hd), ...}, "len": scalar}`` that
-  ``decode_step`` advances in a single call; ``scatter`` writes the
-  updated buffers back through the page tables (allocating the page a
-  growth step crosses into).
+* ``gather`` packs any set of slots — their K/V lengths may differ —
+  into one batched cache ``{"layers": {"k": (L, B, Hkv, W, hd), ...},
+  "len": (B,)}`` that ``decode_step`` advances in a single call, each row
+  at its own position and masked by its own length; ``scatter`` writes
+  the updated buffers back through the page tables (allocating the page
+  a growth step crosses into).  Rows with fewer pages than the widest
+  are padded with the spare page, so padding is read from and written
+  to a page no slot owns, and every position past a row's length holds
+  finite data (spare-page, stale or zero) that the mask weighs by 0.
 
 Gather→compute→scatter round-trips are value-exact (pages are plain
 slices), so the batched path's numerics reduce to ``decode_step``'s own
 row independence — pinned by the batched-vs-B=1 parity tests.
+``shape_buckets`` (slots of equal K/V length) no longer groups the
+engine's dispatches; the CIM simulator (``sim/serve_sim.py``) still
+lowers per bucket.
 
 Each copy is one jitted program per pool side with a stable name in the
 device trace — ``kv_gather``, ``kv_scatter``, ``kv_write`` — taking the
 page ids as an int32 argument, so one program serves every slot set of a
-(bucket size, page count) shape.  The host spans ``kv.gather``,
+(batch size, page count) shape.  The host spans ``kv.gather``,
 ``kv.scatter`` and ``kv.write`` (``repro.obs.spans``) cover each call;
-``scatter`` and ``admit`` read the cache length through ``host_read``.
+``scatter`` and ``admit`` each read the cache length(s) in one
+``host_read``.
 """
 from __future__ import annotations
 
@@ -52,8 +59,9 @@ def shape_buckets(kv_lens: Sequence[int]
     Returns ``[(kv_len, positions), ...]`` where ``positions`` index into
     ``kv_lens``; buckets appear in order of their first member, members
     keep their relative order.  Slots in one bucket share cache shape
-    *and* position counter, so one ``decode_step`` call advances them
-    all.
+    *and* position counter — the unit the CIM simulator lowers a decode
+    step by (``sim/serve_sim.py``); the engine dispatches all slots at
+    once.
     """
     order: List[int] = []
     members: Dict[int, List[int]] = {}
@@ -144,7 +152,8 @@ class PagedKVCache:
         self.page_size = min(int(page_size), width)
         self.pages_per_slot = -(-width // self.page_size)
         self.num_pages = slots * self.pages_per_slot
-        shape = (self.num_pages, num_layers, kv_heads, self.page_size,
+        self._spare = self.num_pages       # pads ragged rows; never free
+        shape = (self.num_pages + 1, num_layers, kv_heads, self.page_size,
                  head_dim)
         self._k_pool = jnp.zeros(shape, dtype)
         self._v_pool = jnp.zeros(shape, dtype)
@@ -234,57 +243,58 @@ class PagedKVCache:
         self._free.extend(entry.pages)
 
     # ------------------------------------------------------------------
-    # Bucket gather / scatter
+    # Batch gather / scatter
     # ------------------------------------------------------------------
 
-    def gather(self, slot_ids: Sequence[int]):
-        """Pack one shape bucket into a batched decode cache.
+    def _page_ids(self, entries: Sequence[_SlotEntry]) -> np.ndarray:
+        """(B, npg) page ids, each row padded to the widest row's page
+        count with the spare page."""
+        npg = max(len(e.pages) for e in entries)
+        return np.asarray([e.pages + [self._spare] * (npg - len(e.pages))
+                           for e in entries], np.int32)
 
-        All slots must hold equal lengths (equal length <=> equal
-        position counter <=> one shared RoPE position — the bucket
-        invariant).  Returns ``{"layers": {"k": (L, B, Hkv, W, hd),
-        "v": ...}, "len": scalar}`` ready for one ``decode_step`` call.
+    def gather(self, slot_ids: Sequence[int]):
+        """Pack slots of any K/V lengths into one batched decode cache.
+
+        Returns ``{"layers": {"k": (L, B, Hkv, W, hd), "v": ...},
+        "len": (B,) int32}`` ready for one ``decode_step`` call; each row
+        holds its slot's pages, then spare-page data and zeros.
         """
         with span("kv.gather"):
             entries = [self._table[s] for s in slot_ids]
-            lens = {e.length for e in entries}
-            if len(lens) != 1:
-                raise ValueError(f"bucket slots {list(slot_ids)} hold "
-                                 f"unequal lengths {sorted(lens)}")
-            length = entries[0].length
-            npg = self._pages_for(length)
-            if npg == 0:
+            lens = jnp.asarray([e.length for e in entries], jnp.int32)
+            if not any(e.pages for e in entries):
                 k = jnp.zeros((self.num_layers, len(entries), self.kv_heads,
                                self.width, self.head_dim),
                               self._k_pool.dtype)
-                v = k
-            else:
-                ids = np.asarray([e.pages[:npg] for e in entries], np.int32)
-                k = kv_gather(self._k_pool, ids, self.width)
-                v = kv_gather(self._v_pool, ids, self.width)
-            return {"layers": {"k": k, "v": v},
-                    "len": jnp.asarray(length, jnp.int32)}
+                return {"layers": {"k": k, "v": k}, "len": lens}
+            ids = self._page_ids(entries)
+            return {"layers": {"k": kv_gather(self._k_pool, ids, self.width),
+                               "v": kv_gather(self._v_pool, ids, self.width)},
+                    "len": lens}
 
     def scatter(self, slot_ids: Sequence[int], cache) -> None:
-        """Write one advanced bucket cache back through the page tables,
+        """Write one advanced batch cache back through the page tables,
         allocating the page each slot's growth step crossed into.
 
-        One ``kv_scatter`` per pool side for the whole bucket (equal
-        lengths => equal page counts) — a per-slot write-back loop would
-        cost as many dispatches as the batching saved.
+        ``cache["len"]`` is read in one ``host_read``: ``(B,)``, one
+        length per row, or a scalar every row shares.  One
+        ``kv_scatter`` per pool side for the whole batch — a per-slot
+        write-back loop would cost as many dispatches as the batching
+        saved.
         """
         with span("kv.scatter"):
-            new_len = int(host_read(cache["len"], self._syncs))
+            new_lens = np.broadcast_to(
+                host_read(cache["len"], self._syncs), (len(slot_ids),))
             entries = [self._table[s] for s in slot_ids]
-            for e in entries:
-                if new_len < e.length:
+            for e, n in zip(entries, new_lens.tolist()):
+                if n < e.length:
                     raise ValueError("scatter would shrink a slot's cache")
-                self._alloc(e, new_len)
-                e.length = new_len
-            npg = self._pages_for(new_len)
-            if npg == 0:
+                self._alloc(e, n)
+                e.length = n
+            if not any(e.pages for e in entries):
                 return
-            ids = np.asarray([e.pages[:npg] for e in entries], np.int32)
+            ids = self._page_ids(entries)
             self._k_pool = kv_scatter(self._k_pool, ids,
                                       cache["layers"]["k"])
             self._v_pool = kv_scatter(self._v_pool, ids,

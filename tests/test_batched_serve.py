@@ -4,13 +4,16 @@ the coarse==fine serve-sim equivalence.
 
 Invariant map:
 
-* ``PagedKVCache`` gather→scatter round-trips are value-exact and pages
-  allocate/free with slot lifecycle (a leak would exhaust the pool);
+* ``PagedKVCache`` gather→scatter round-trips are value-exact, for slots
+  of equal or unequal K/V lengths, write nothing into another slot's
+  pages, and pages allocate/free with slot lifecycle (a leak would
+  exhaust the pool);
 * ``plan_decode_buckets`` partitions the whole-step plan exactly —
   per-bucket HBM predictions sum to ``plan_decode_step``'s;
 * the batched engine emits token-for-token what the per-slot engine
-  emits (row independence end-to-end), while issuing
-  ``decode_batches < decode_calls`` dispatches; non-pageable cache trees
+  emits (row independence end-to-end), while issuing one dispatch per
+  step that decodes, whatever its slots' K/V lengths
+  (``decode_ragged`` counts the mixed ones); non-pageable cache trees
   (SSM/hybrid) fall back transparently;
 * ``simulate_serve(decode_lowering="coarse")`` reproduces fine's
   cycles, bytes, and metrics *exactly* with strictly fewer trace events.
@@ -81,7 +84,7 @@ def test_paged_pool_roundtrip_and_growth():
     assert pool.pages_in_use == 4                 # ceil(9/8) = 2 each
     g = pool.gather([0, 1])
     assert g["layers"]["k"].shape == (2, 2, 2, 24, 8)
-    assert int(g["len"]) == 9
+    assert g["len"].tolist() == [9, 9]
     # valid prefix round-trips exactly, per slot
     assert jnp.array_equal(g["layers"]["k"][:, 0, :, :9],
                            c0["layers"]["k"][:, 0, :, :9])
@@ -110,12 +113,68 @@ def test_paged_pool_guards():
     with pytest.raises(ValueError, match="already admitted"):
         pool.admit(0, _cache(length=5))
     pool.admit(1, _cache(length=9))
-    with pytest.raises(ValueError, match="unequal"):
-        pool.gather([0, 1])
+    g = pool.gather([0, 1])                   # unequal lengths pack
+    assert g["len"].tolist() == [5, 9]
+    with pytest.raises(ValueError, match="shrink"):
+        pool.scatter([0, 1], dict(g, len=jnp.asarray([6, 8], jnp.int32)))
     assert not PagedKVCache.supports({"layers": {"attn": 1, "ssm": 2},
                                       "len": 0})
     assert not PagedKVCache.supports(jnp.zeros(3))
     assert PagedKVCache.supports(_cache())
+
+
+def test_paged_pool_ragged_roundtrip():
+    """Slots of unequal lengths (one page, two pages, three pages) pack
+    into one batch and write back as per-slot writes would: each slot's
+    pages hold what one-slot gather/scatter leaves there, a slot outside
+    the batch keeps its pages bitwise, and padding only reads and writes
+    the spare page, which no slot is ever given."""
+    def pool_with(lengths):
+        pool = PagedKVCache(slots=4, num_layers=2, kv_heads=2, width=24,
+                            head_dim=8, dtype=jnp.float32, page_size=8)
+        for s, n in enumerate(lengths):
+            pool.admit(s, _cache(length=n, seed=s))
+        return pool
+
+    def advance(cache):
+        """One decode step's writes: a marker at each row's position."""
+        n = cache["len"]
+        at = jnp.arange(24)[None, None, None, :, None] \
+            == n[None, :, None, None, None]
+        layers = {side: jnp.where(at, val, cache["layers"][side])
+                  for side, val in (("k", 1.5), ("v", -2.5))}
+        return {"layers": layers, "len": n + 1}
+
+    lengths = (5, 16, 9, 20)
+    batched, solo = pool_with(lengths), pool_with(lengths)
+    before = pool_with(lengths)
+    rows = [0, 1, 2]                          # slot 3 sits out
+    g = batched.gather(rows)
+    assert g["len"].tolist() == [5, 16, 9]
+    for i, s in enumerate(rows):
+        n = lengths[s]
+        assert jnp.array_equal(g["layers"]["k"][:, i, :, :n],
+                               _cache(length=n, seed=s)["layers"]["k"]
+                               [:, 0, :, :n])
+    assert bool(jnp.isfinite(g["layers"]["k"]).all())
+    batched.scatter(rows, advance(g))
+    for s in rows:
+        solo.scatter([s], advance(solo.gather([s])))
+    assert batched.len_of(1) == 17 and batched.pages_in_use == 9
+    spare = batched.num_pages
+    for s in range(4):
+        pages = batched.page_table(s)
+        assert pages == solo.page_table(s) and spare not in pages
+        for pool_b, pool_s in ((batched._k_pool, solo._k_pool),
+                               (batched._v_pool, solo._v_pool)):
+            assert jnp.array_equal(pool_b[jnp.asarray(pages)],
+                                   pool_s[jnp.asarray(pages)])
+    ids3 = jnp.asarray(before.page_table(3))
+    assert jnp.array_equal(batched._k_pool[ids3], before._k_pool[ids3])
+    # a second ragged step reads back what the first wrote
+    g2 = batched.gather(rows)
+    assert g2["len"].tolist() == [6, 17, 10]
+    assert float(g2["layers"]["v"][0, 1, 0, 16, 0]) == -2.5
 
 
 def test_paged_pool_exhaustion_is_loud():
@@ -203,6 +262,8 @@ def test_batched_engine_sliding_window_ring_wrap():
 
 
 def test_step_record_buckets_partition_decoded():
+    """One bucket entry per step that decodes: every decoded rid, in the
+    schedule's order, under the widest K/V length of the dispatch."""
     eng = Engine(SMOKE, _params(), slots=3, max_len=32)
     for r in _requests(SMOKE):
         eng.submit(r)
@@ -211,14 +272,30 @@ def test_step_record_buckets_partition_decoded():
         eng.last_schedule.decode_steps.values())
     for rec in eng.step_log:
         if not rec.decoded:
+            assert rec.buckets is None
             continue
-        assert rec.buckets is not None
-        rids = [rid for _, rs in rec.buckets for rid in rs]
-        assert sorted(rids) == sorted(rec.decoded)
-        for kv, rs in rec.buckets:
-            for rid in rs:
-                i = rec.decoded.index(rid)
-                assert rec.kv_lens[i] == kv
+        ((kv, rids),) = rec.buckets
+        assert rids == rec.decoded
+        assert kv == max(rec.kv_lens)
+
+
+@pytest.mark.parametrize("cfg", [SMOKE, SLIDING], ids=["full", "ring"])
+def test_one_dispatch_per_decoding_step(cfg):
+    """Ragged traffic (every slot at its own K/V length, the sliding
+    config's ring wrapping at 16): one decode_step per step that decodes,
+    the per-slot path's tokens, and ``decode_ragged`` counting the steps
+    whose rows hold more than one length."""
+    (engb, toksb), (_, tokss) = _run_both(
+        cfg, req_kw={"n": 4, "plen": (3, 14), "max_new": (4, 14),
+                     "arrival_spread": 2, "seed": 5})
+    assert toksb == tokss
+    decoding = [rec for rec in engb.step_log if rec.decoded]
+    assert engb.decode_batches == len(decoding)
+    ragged = sum(len(set(rec.kv_lens)) > 1 for rec in decoding)
+    counters = engb.stats()["metrics"]["counters"]
+    assert counters["decode_ragged"] == ragged > 0
+    if cfg is SLIDING:                        # the ring wrapped
+        assert max(max(rec.kv_lens) for rec in decoding) > 16
 
 
 def test_batched_disabled_and_fallback_paths():

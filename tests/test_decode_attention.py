@@ -186,3 +186,45 @@ def test_full_width_matches_multi_head_attention():
     mh = multi_head_attention(q, k, v, causal=False, block_q=8,
                               block_k=256)
     assert jnp.max(jnp.abs(out - mh)) < 1e-6
+
+
+@pytest.mark.parametrize("name,lens", [
+    ("starcoder2-7b", (3, 17, 30)),            # full attention, W = 32
+    ("h2o-danube3-4b", (3, 17, 30))],          # ring of W = 16: two wrap
+    ids=["full", "ring"])
+def test_decode_step_per_row_positions(name, lens):
+    """``decode_step`` with a ``(B,)`` ``len`` of unequal positions: each
+    row's logits and new cache row equal, bitwise, that row of the same
+    batch decoded at its scalar position, and a B=1 ``decode_step`` at
+    that position gives the row's token, with logits within
+    ``JNP_ROW_TOL`` of the logit scale (XLA's fp32 reductions depend on
+    the batch size; see the module docstring)."""
+    import jax
+
+    cfg = registry.get_config(name, smoke=True)
+    mod = registry.model_module(cfg)
+    params = mod.init(jax.random.PRNGKey(0), cfg)
+    step = jax.jit(lambda c, t: mod.decode_step(params, cfg, c, t))
+    B = len(lens)
+    shape = mod.init_cache(cfg, B, 32)["layers"]["k"].shape
+    rng = np.random.default_rng(0)
+    layers = {side: jnp.asarray(rng.normal(size=shape), jnp.float32)
+              for side in ("k", "v")}
+    toks = jnp.asarray([[5], [7], [9]], jnp.int32)
+    logits, new = step({"layers": layers,
+                        "len": jnp.asarray(lens, jnp.int32)}, toks)
+    assert new["len"].tolist() == [n + 1 for n in lens]
+    for i, n in enumerate(lens):
+        pos = jnp.asarray(n, jnp.int32)
+        same, same_c = step({"layers": layers, "len": pos}, toks)
+        assert jnp.array_equal(logits[i], same[i]), f"row {i}"
+        for side in ("k", "v"):
+            assert jnp.array_equal(new["layers"][side][:, i],
+                                   same_c["layers"][side][:, i])
+        row = jax.tree.map(lambda a: a[:, i:i + 1], layers)
+        solo, _ = step({"layers": row, "len": pos}, toks[i:i + 1])
+        assert int(jnp.argmax(solo[0, 0])) == int(jnp.argmax(logits[i, 0]))
+        scale = float(jnp.max(jnp.abs(solo)))
+        np.testing.assert_allclose(np.asarray(logits[i:i + 1]),
+                                   np.asarray(solo), rtol=0,
+                                   atol=JNP_ROW_TOL * scale)

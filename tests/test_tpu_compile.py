@@ -86,19 +86,36 @@ def test_decode_attention_compiles_natively(one_chip, batch):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_minitron_decode_step_fits_one_chip(one_chip):
-    """minitron-4b's serving step at 4 slots x 2048 positions: parameters,
-    cache, new cache and temporaries within one chip's HBM."""
+def _minitron_decode_step_bytes(one_chip, per_row: bool) -> int:
+    """Parameters, cache, new cache and temporaries of minitron-4b's
+    serving step at 4 slots x 2048 positions, compiled for one chip;
+    ``per_row``: the slots decode at positions of their own (``(4,)``
+    ``len``, the engine's pooled step)."""
     cfg = MINITRON
     mod = registry.model_module(cfg)
     on_chip = lambda tree: jax.tree.map(                      # noqa: E731
         lambda a: _shape(one_chip, a.shape, a.dtype), tree)
     params = on_chip(registry.param_specs(cfg))
     cache = on_chip(jax.eval_shape(lambda: mod.init_cache(cfg, 4, 2048)))
+    if per_row:
+        cache["len"] = _shape(one_chip, (4,), jnp.int32)
     toks = _shape(one_chip, (4, 1), jnp.int32)
     c = _compile(lambda p, c, t: mod.decode_step(p, cfg, c, t),
                  params, cache, toks)
     mem = c.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes)
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
+def test_minitron_decode_step_fits_one_chip(one_chip):
+    """minitron-4b's serving step at 4 slots x 2048 positions: parameters,
+    cache, new cache and temporaries within one chip's HBM."""
+    total = _minitron_decode_step_bytes(one_chip, per_row=False)
+    assert total < HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
+
+
+def test_minitron_per_row_decode_step_fits_one_chip(one_chip):
+    """The same step with one position per slot compiles for the chip
+    and fits as the shared-position step does."""
+    total = _minitron_decode_step_bytes(one_chip, per_row=True)
     assert total < HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
